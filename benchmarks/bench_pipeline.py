@@ -25,9 +25,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, re, time
 import jax, jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.parallel.pipeline import pipeline_apply
 
-mesh = jax.make_mesh((8,), ("stage",))
+mesh = jax.make_mesh((8,), ("stage",), axis_types=(AxisType.Auto,))
 S, M, B, D = 8, 16, 8, 256
 
 def stage_fn(p, x):
@@ -70,6 +71,9 @@ def run() -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
     env.pop("XLA_FLAGS", None)
+    # The child measures 8 virtual CPU devices.  Pin it to the CPU so it
+    # never contends for an accelerator a parent process may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _WORKER],
                           capture_output=True, text=True, env=env,
                           timeout=600)

@@ -22,12 +22,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.parallel.pipeline import pipeline_apply, pipeline_reference
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("stage",))
+    mesh = jax.make_mesh((8,), ("stage",), axis_types=(AxisType.Auto,))
     S, M, B, D = 8, 16, 8, 256
 
     def stage_fn(p, x):

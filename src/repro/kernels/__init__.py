@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels (each with a jnp oracle in ref.py).  Callers pass
+# ``interpret=True`` explicitly to run one off the TPU.

@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.7 names the TPU compiler options TPUCompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -149,7 +145,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qm, km, vm)

@@ -25,10 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.7 names the TPU compiler options TPUCompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _nbb_matmul_kernel(a_hbm, b_hbm, o_ref, a_ring, b_ring, acc_ref,
                        in_sems, *, bm, bn, bk, n_k):
@@ -97,8 +93,8 @@ def nbb_matmul(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
         kernel,
         grid=(M // bm, N // bn),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),   # a stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # b stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # a stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # b stays in HBM
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
@@ -108,7 +104,7 @@ def nbb_matmul(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
             pltpu.VMEM((bm, bn), jnp.float32),       # accumulator
             pltpu.SemaphoreType.DMA((2, 2)),         # per-slot, per-operand
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(a, b)

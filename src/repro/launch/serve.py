@@ -14,8 +14,10 @@ from __future__ import annotations
 import argparse
 import os
 import signal
+import sys
 import threading
 import time
+from typing import Optional, Tuple
 
 import jax
 import numpy as np
@@ -23,6 +25,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config, list_archs
 from repro.core import nbb
 from repro.core.host_queue import SpscQueue
+from repro.launch.compile_cache import place_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import ServeEngine
 from repro.serve.overload import (
@@ -39,7 +42,7 @@ def _pct(sorted_vals, q: float) -> float:
     return sorted_vals[min(int(len(sorted_vals) * q), len(sorted_vals) - 1)]
 
 
-def main(argv=None) -> ServeEngine:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m", choices=list_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -119,11 +122,19 @@ def main(argv=None) -> ServeEngine:
                     help="restore before serving: a snapshot file, or a "
                          "snapshot directory (newest valid snapshot + "
                          "journal replay); prints the restore report")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def build_engine(args: argparse.Namespace, seed: int = 0
+                 ) -> Tuple[ServeEngine, Optional[OverloadPolicy],
+                            Optional[str]]:
+    """Config -> model -> random params from ``seed`` -> scheduler and
+    pool sizing -> ``ServeEngine``, as the launcher's options ask.
+    Returns the engine, its overload policy and its snapshot directory
+    (each None when off)."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(seed))
     scheduler = args.scheduler
     if scheduler is None:
         # Chunked admission needs position-indexed caches; recurrent
@@ -178,6 +189,26 @@ def main(argv=None) -> ServeEngine:
                       tick_retries=args.tick_retries,
                       snapshot_dir=snapshot_dir,
                       snapshot_every=args.snapshot_every)
+    return eng, overload, snapshot_dir
+
+
+def serve_failure(eng: ServeEngine) -> Optional[str]:
+    """Why a serve run must not count as a success, or None: the engine
+    died, or it ended requests with a ``FailedStatus``."""
+    if eng.dead is not None:
+        return f"engine died: {eng.dead}"
+    n = eng.stats["requests_failed"]
+    if n:
+        return f"{n} requests failed; first: {eng.first_fault}"
+    return None
+
+
+def main(argv=None) -> ServeEngine:
+    args = build_parser().parse_args(argv)
+    place_compile_cache()
+    eng, overload, snapshot_dir = build_engine(args)
+    cfg = eng.model.cfg
+    scheduler = eng.scheduler
     if args.restore is not None and snapshot_dir is not None:
         report = (eng.restore_latest() if os.path.isdir(args.restore)
                   else eng.restore(args.restore))
@@ -343,6 +374,12 @@ def main(argv=None) -> ServeEngine:
               f"(evictions {cstats['evictions']})  "
               f"shared pages peak {pstats['shared_pages_peak']}  "
               f"cow copies {pstats['cow_copy_bytes'] / 1024:.0f} KiB")
+    failure = serve_failure(eng)
+    if failure is None and len(lat) < n:
+        failure = f"{n - len(lat)} of {n} requests never reached a client"
+    if failure is not None:
+        print(f"serve failed: {failure}", file=sys.stderr)
+        sys.exit(1)
     return eng
 
 

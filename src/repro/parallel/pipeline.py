@@ -41,8 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import shard_map_compat
-
 
 def _stage_slice(stage_params, n_stages):
     """shard_map hands each device its [1, ...]-leading slice; drop it."""
@@ -137,10 +135,11 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         # traffic and hid the barrier-vs-ring difference.)
         return outs[None]
 
-    shard_f = shard_map_compat(
+    shard_f = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(axis), P()),       # params split by stage; mbs replicated
         out_specs=P(axis),             # [n_stages, n_micro, mb, ...]
+        check_vma=False,
     )
     return shard_f(stage_params, microbatches)
 

@@ -18,17 +18,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: the public API (with
-    ``check_vma``) when present, else ``jax.experimental.shard_map``
-    (whose equivalent knob is ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check)
-
 # Default rules for the production meshes of DESIGN.md §7.
 # "batch" spreads over pod+data; "model"-parallel dims over the model axis.
 DEFAULT_RULES: Dict[str, MeshAxes] = {

@@ -875,6 +875,10 @@ class ServeEngine:
                       # and pages quarantined after poisoned writes.
                       "faults_injected": 0, "requests_failed": 0,
                       "leases_reaped": 0, "pages_quarantined": 0,
+                      # Every exception the tick watchdog caught,
+                      # retried or not: a dispatch error never passes
+                      # unseen (``first_fault`` keeps the first reason).
+                      "tick_faults": 0,
                       # Crash-recovery counters (DESIGN.md §14):
                       # snapshots written / bytes of the newest one,
                       # restores performed, journal records replayed as
@@ -898,6 +902,9 @@ class ServeEngine:
         # every receive surface observes it and resolves with a typed
         # falsy FailedStatus instead of hanging.
         self.dead: Optional[str] = None
+        # The first tick fault or engine-ended request's reason, for
+        # whoever reports a failed run (stats only count them).
+        self.first_fault: Optional[str] = None
         self._tick_failures = 0         # consecutive failed ticks (watchdog)
         self._reaped: set = set()       # clients whose lease was reaped
         # -- crash recovery (DESIGN.md §14) --------------------------------
@@ -1077,6 +1084,7 @@ class ServeEngine:
         pre-admission (minus any pages quarantine pinned first)."""
         req = slot.request
         req.status = FailedStatus(reason)
+        self._note_fault(reason)
         req.tokens_out = slot.outs[:slot.generated].astype(np.int32)
         req.done_t = time.monotonic()
         if req.fsm.cas(states.REQUEST_RECEIVED, states.REQUEST_CANCELLED):
@@ -1092,6 +1100,7 @@ class ServeEngine:
         """Engine-initiated terminal for a request that never reached a
         slot (lease reap / dead-engine intake drain)."""
         req.status = FailedStatus(reason)
+        self._note_fault(reason)
         if req.fsm.cas(states.REQUEST_VALID, states.REQUEST_CANCELLED):
             self.stats["requests_failed"] += 1
         else:
@@ -1100,6 +1109,10 @@ class ServeEngine:
         if req.tokens_out is None:
             req.tokens_out = np.zeros((0,), np.int32)
         self._respond(req)
+
+    def _note_fault(self, reason: str) -> None:
+        if self.first_fault is None:
+            self.first_fault = reason
 
     def _client_rings(self, client_id: int) -> List[object]:
         """The client's private intake ring(s): one flat MPSC ring, or
@@ -1177,6 +1190,8 @@ class ServeEngine:
         uncommitted span first — the engine thread is their producer, so
         the rollback is unconditionally legal."""
         retryable = bool(getattr(exc, "retryable", True))
+        self.stats["tick_faults"] += 1
+        self._note_fault(f"tick failed: {exc!r}")
         self._tick_failures += 1
         if retryable and self._tick_failures <= self.tick_retries:
             return 0, True              # transient: next tick retries
@@ -1981,6 +1996,35 @@ class ServeEngine:
                     donate_argnums=(1,))
             self._jit_chunked[k] = fn
         return fn
+
+    def warmup(self) -> int:
+        """Compile every program the chunked tick can dispatch (chunk
+        only, chunk + K decode steps, K decode steps; K = 1..k_max) by
+        running each once with every row masked: no KV is written and no
+        host state moves.  Call on an idle engine before serving, so
+        compiling is set-up and not serve time.  Returns the number of
+        programs run."""
+        if self.scheduler not in ("slot_chunked", "slot_paged"):
+            raise ValueError(f"warmup covers the chunked tick, not "
+                             f"{self.scheduler!r}")
+        if any(s.request is not None for s in self.slots):
+            raise RuntimeError("warmup needs an idle engine")
+        B = self.max_batch
+        zeros = jnp.zeros((B,), jnp.int32)
+        chunk = jnp.zeros((B, self.chunk_tokens), jnp.int32)
+        eos = jnp.full((B,), -1, jnp.int32)
+        caches = None
+        for k in range(self.k_max + 1):
+            decode = (zeros, zeros, zeros, eos) if k else ()
+            *_, caches = self._chunked_fn(k)(self.params, self._take_caches(),
+                                             chunk, zeros, zeros, *decode)
+            self._give_caches(caches)
+            if k:
+                _, caches = self._loop_fn(k)(self.params, self._take_caches(),
+                                             zeros, zeros, zeros, eos)
+                self._give_caches(caches)
+        jax.block_until_ready(caches)
+        return 2 * self.k_max + 1
 
     def _reject_streaming(self, slot: DecodeSlot) -> None:
         """Mid-stream pool exhaustion (chunked admission): the whole

@@ -11,15 +11,14 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.parallel.pipeline import pipeline_apply, pipeline_reference
 from repro.parallel.compression import compressed_psum
-from repro.parallel.sharding import shard_map_compat
 
 
 def check_pipeline_schedules():
-    mesh = jax.make_mesh((8,), ("stage",))
+    mesh = jax.make_mesh((8,), ("stage",), axis_types=(AxisType.Auto,))
     n_stages, n_micro, mb, d = 8, 12, 4, 16
 
     def stage_fn(p, x):
@@ -46,7 +45,7 @@ def check_pipeline_schedules():
 def check_pipeline_collective_bytes():
     """nbb must move ~1/S the collective bytes of barrier (paper's point)."""
     import re
-    mesh = jax.make_mesh((8,), ("stage",))
+    mesh = jax.make_mesh((8,), ("stage",), axis_types=(AxisType.Auto,))
     n_stages, n_micro, mb, d = 8, 8, 4, 128
 
     def stage_fn(p, x):
@@ -77,7 +76,7 @@ def check_pipeline_collective_bytes():
 
 
 def check_compressed_psum():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     k = jax.random.PRNGKey(3)
     # per-shard gradients [8, ...]
     g_sh = jax.random.normal(k, (8, 32, 16), jnp.float32)
@@ -88,8 +87,8 @@ def check_compressed_psum():
                                       n_shards=8)
         return mean["w"], new_e["w"][None]
 
-    f = shard_map_compat(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                         out_specs=(P(), P("data")))
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                      out_specs=(P(), P("data")), check_vma=False)
     err = jnp.zeros((8, 32, 16), jnp.float32)
     mean, err1 = f(g_sh, err)
     true_mean = g_sh.mean(0)
