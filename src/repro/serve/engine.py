@@ -147,6 +147,7 @@ class Request:
         default_factory=lambda: states.request_cell())
     tokens_out: Optional[np.ndarray] = None
     submit_t: float = 0.0
+    bind_t: float = 0.0                 # first bound to a decode slot
     first_token_t: float = 0.0          # harvest time of token 0 (TTFT)
     done_t: float = 0.0
     token_ts: List[float] = dataclasses.field(default_factory=list)
@@ -718,6 +719,26 @@ class ParkedSeq:
     bypassed: int = 0
 
 
+@dataclasses.dataclass
+class _ChunkPlan:
+    """One dispatch of the chunked tick, as the host planned it: the
+    prompt chunks that ride it, the rows that keep decoding, the block
+    length, and the operands (device-copied only at dispatch)."""
+
+    chunks: List[Tuple[DecodeSlot, int, bool]]   # (slot, tokens, final)
+    active: List[DecodeSlot]            # rows already decoding
+    k: int                              # decode steps; 0 = chunk only
+    chunk_args: Tuple[np.ndarray, ...]  # chunk rows, starts, valid counts
+    decode_args: Tuple[np.ndarray, ...]  # cur, pos, remaining budget, eos
+    prompt_tokens: int                  # chunk positions that are not pad
+
+
+# Host spans of the tick (``serve.*``) on the profiler's clock, beside the
+# device's ops.  Always on: about a microsecond each while no profiler
+# records.
+_span = jax.profiler.TraceAnnotation
+
+
 def _write_slot_caches(full, one, slot):
     """Copy a B=1 prefilled cache into row ``slot`` of the batch cache.
 
@@ -884,7 +905,17 @@ class ServeEngine:
                       # restores performed, journal records replayed as
                       # fresh submissions, and in-process restarts.
                       "snapshots": 0, "snapshot_bytes": 0, "restores": 0,
-                      "replayed_requests": 0, "restarts": 0}
+                      "replayed_requests": 0, "restarts": 0,
+                      # Tick accounting (chunked/paged tick): first binds
+                      # and their summed wait since submit; ticks that
+                      # dispatched, split into the host's wait on the
+                      # device (``serve.sync``) and the rest of the tick,
+                      # when the device has nothing queued; prompt
+                      # positions (not padding) in dispatched chunks.
+                      "binds": 0, "queue_wait_ns": 0, "ticks_worked": 0,
+                      "tick_host_ns": 0, "tick_sync_ns": 0,
+                      "chunk_tokens": 0}
+        self._sync_ns: Optional[int] = None     # this tick's serve.sync
         # Append-only log of fail-fast oversize rejects (written by
         # client threads in submit_i; list.append is the atomic).
         self.oversize_log: List[int] = []
@@ -1476,6 +1507,13 @@ class ServeEngine:
         self._pos[slot.index] = 0
         self._cur[slot.index] = 0
         self.stats["admitted"] += 1
+        if not req.bind_t:
+            # First bind only: the request's wait in the intake queue.
+            # A restored request may carry another process's clock.
+            req.bind_t = time.monotonic()
+            self.stats["binds"] += 1
+            self.stats["queue_wait_ns"] += max(
+                0, int((req.bind_t - req.submit_t) * 1e9))
         if self._ov is not None:
             # WFQ accounting at BIND, not pop: only work that actually
             # claims capacity advances the client's virtual time, and
@@ -1901,33 +1939,45 @@ class ServeEngine:
         armed, silent clients are reaped first."""
         if self.dead is not None:
             return 0, False
-        reaped = self._reap_leases() if self.lease_s is not None else False
-        self._in_tick = True
-        try:
-            if self.scheduler in ("slot_chunked", "slot_paged"):
-                served, worked = self._tick_chunked()
-            elif self.scheduler == "slot_fused":
-                served, worked = self._tick_fused()
-            else:
-                served, worked = self._tick_scalar()
-            self._tick_failures = 0
-        except Exception as exc:        # noqa: BLE001 — watchdog boundary
-            served, worked = self._on_tick_fault(exc)
-        finally:
-            self._in_tick = False
-        # Tick boundary: the one point where host state is consistent
-        # (no half-harvested dispatch, no half-claimed admission), so
-        # the one point snapshots are taken (DESIGN.md §14).
-        self._ticks += 1
-        if (self._snap_dir is not None and self.dead is None
-                and (self._snap_requested
-                     or (self._snap_every is not None
-                         and self._ticks % self._snap_every == 0))):
-            self._snap_requested = False
-            with contextlib.suppress(Exception):
-                self.save_snapshot()
-        if self.faults is not None:
-            self.stats["faults_injected"] = self.faults.n_fired
+        t_tick = time.monotonic_ns()
+        self._sync_ns = None
+        with jax.profiler.StepTraceAnnotation("serve.tick",
+                                              step_num=self._ticks):
+            reaped = (self._reap_leases() if self.lease_s is not None
+                      else False)
+            self._in_tick = True
+            try:
+                if self.scheduler in ("slot_chunked", "slot_paged"):
+                    served, worked = self._tick_chunked()
+                elif self.scheduler == "slot_fused":
+                    served, worked = self._tick_fused()
+                else:
+                    served, worked = self._tick_scalar()
+                self._tick_failures = 0
+            except Exception as exc:    # noqa: BLE001 — watchdog boundary
+                served, worked = self._on_tick_fault(exc)
+            finally:
+                self._in_tick = False
+            # Tick boundary: the one point where host state is consistent
+            # (no half-harvested dispatch, no half-claimed admission), so
+            # the one point snapshots are taken (DESIGN.md §14).
+            self._ticks += 1
+            if (self._snap_dir is not None and self.dead is None
+                    and (self._snap_requested
+                         or (self._snap_every is not None
+                             and self._ticks % self._snap_every == 0))):
+                self._snap_requested = False
+                with contextlib.suppress(Exception):
+                    self.save_snapshot()
+            if self.faults is not None:
+                self.stats["faults_injected"] = self.faults.n_fired
+        if self._sync_ns is not None:
+            # The engine syncs every tick, so outside ``serve.sync`` the
+            # device has nothing queued: that rest is the tick's host time.
+            self.stats["ticks_worked"] += 1
+            self.stats["tick_sync_ns"] += self._sync_ns
+            self.stats["tick_host_ns"] += (time.monotonic_ns() - t_tick
+                                           - self._sync_ns)
         return served, worked or reaped
 
     def _finished(self, req: Request, tok: int, generated: int,
@@ -1965,14 +2015,19 @@ class ServeEngine:
             k = min(k, self.k_free)
         return max(1, k)
 
+    # Each step program is a function of its own name, so that a profiler
+    # trace shows it as ``jit_serve_decode_k4`` and not ``jit__lambda``.
     def _loop_fn(self, k: int):
         fn = self._jit_loops.get(k)
         if fn is None:
             model, max_len = self.model, self.max_len
-            fn = jax.jit(
-                lambda p, c, cur, pos, rem, eos: model.decode_loop(
-                    p, c, cur, pos, rem, eos, k=k, max_len=max_len),
-                donate_argnums=(1,))
+
+            def serve_decode(p, c, cur, pos, rem, eos):
+                return model.decode_loop(p, c, cur, pos, rem, eos, k=k,
+                                         max_len=max_len)
+
+            serve_decode.__name__ = f"serve_decode_k{k}"
+            fn = jax.jit(serve_decode, donate_argnums=(1,))
             self._jit_loops[k] = fn
         return fn
 
@@ -1984,16 +2039,17 @@ class ServeEngine:
         if fn is None:
             model, max_len = self.model, self.max_len
             if k == 0:
-                fn = jax.jit(
-                    lambda p, c, ch, st, nv: model.prefill_chunk_into(
-                        p, c, ch, st, nv),
-                    donate_argnums=(1,))
+                def serve_chunk(p, c, ch, st, nv):
+                    return model.prefill_chunk_into(p, c, ch, st, nv)
+
+                fn = jax.jit(serve_chunk, donate_argnums=(1,))
             else:
-                fn = jax.jit(
-                    lambda p, c, ch, st, nv, cur, pos, rem, eos:
-                    model.chunked_block(p, c, ch, st, nv, cur, pos, rem,
-                                        eos, k=k, max_len=max_len),
-                    donate_argnums=(1,))
+                def serve_chunk_decode(p, c, ch, st, nv, cur, pos, rem, eos):
+                    return model.chunked_block(p, c, ch, st, nv, cur, pos,
+                                               rem, eos, k=k, max_len=max_len)
+
+                serve_chunk_decode.__name__ = f"serve_chunk_decode_k{k}"
+                fn = jax.jit(serve_chunk_decode, donate_argnums=(1,))
             self._jit_chunked[k] = fn
         return fn
 
@@ -2155,7 +2211,6 @@ class ServeEngine:
             rem_v[s.index] = s.request.max_tokens - s.generated
             eos_v[s.index] = s.request.eos_id
         self._fault_raise("engine.dispatch")    # pre-device: retry is safe
-        t0 = time.monotonic()
         # K=1 rides the same donated decode_loop trace (a scan of one
         # decode_step): uniform harvest below, and the persistent cache
         # is updated in place for every block size, never copied.
@@ -2165,19 +2220,17 @@ class ServeEngine:
             jnp.asarray(eos_v))
         blk = np.asarray(blk_dev).astype(np.int64)
         self.stats["host_syncs"] += 1   # the ONE sync for the whole block
-        t1 = time.monotonic()
+        now = time.monotonic()
         self._fault_raise("engine.sync", retryable=False)
-        served += self._harvest_block(active, blk, k, t0, t1)
+        served += self._harvest_block(active, blk, k, now)
         return served, True
 
     def _harvest_block(self, active: List[DecodeSlot], blk: np.ndarray,
-                       k: int, t0: float, t1: float,
-                       joined: Tuple[DecodeSlot, ...] = ()) -> int:
+                       k: int, now: float) -> int:
         """Harvest one fetched [B, K] token block (shared by the fused
         and chunked schedulers): valid tokens form a per-row prefix
-        (device masking stops emission at EOS/budget/max_len).  Rows in
-        ``joined`` also produced their prefill token in this same
-        dispatch, so their k+1 tokens share the interpolation window.
+        (device masking stops emission at EOS/budget/max_len).  Every
+        token is stamped ``now``, when the host received the block.
         Returns requests retired."""
         served = 0
         for s in active:
@@ -2186,15 +2239,9 @@ class ServeEngine:
             row = blk[s.index]
             n_valid = int((row >= 0).sum())
             first_pos = s.generated
-            nb = 1 if s in joined else 0
-            for j in range(n_valid):
-                s.outs[s.generated] = row[j]
-                s.generated += 1
-                # Per-token timestamps interpolated within the block:
-                # the block produced its tokens at a uniform device
-                # cadence between t0 and t1.
-                req.token_ts.append(
-                    t0 + (j + 1 + nb) * (t1 - t0) / (k + nb))
+            s.outs[first_pos:first_pos + n_valid] = row[:n_valid]
+            s.generated += n_valid
+            req.token_ts.extend([now] * n_valid)
             s.pos += n_valid
             self._pos[s.index] = s.pos
             self._cur[s.index] = int(row[n_valid - 1])
@@ -2230,9 +2277,54 @@ class ServeEngine:
         per-slot block table instead of the dense batch cache, so the
         same dispatch discipline gains length-proportional residency
         and zero-copy swap-in (DESIGN.md §10).  Token sequences are
-        byte-identical across slot_fused/slot_chunked/slot_paged."""
-        served = 0
-        worked = self._sweep_in()
+        byte-identical across slot_fused/slot_chunked/slot_paged.
+
+        Each phase is a ``serve.*`` span inside ``serve.tick``: intake,
+        plan, block_table, dispatch, sync, harvest."""
+        with _span("serve.intake"):
+            worked = self._sweep_in()
+        with _span("serve.plan"):
+            planned, plan = self._plan_chunked()
+        worked = worked or planned
+        if plan is None:
+            return 0, worked
+        with _span("serve.block_table"):
+            caches = self._take_caches()
+        # 3) ONE dispatch: chunk and K-step block fused when both exist.
+        # Dispatch probe sits here — after ALL host bookkeeping, before
+        # any device work — so a retried tick reassembles idempotently
+        # (extend claims 0 new pages, ensure_private finds nothing
+        # shared) and redispatches the identical work.
+        self._fault_raise("engine.dispatch")
+        with _span("serve.dispatch"):
+            tok_dev, blk_dev, caches = self._dispatch_chunked(plan, caches)
+            self._give_caches(caches)
+        t_sync = time.monotonic_ns()
+        with _span("serve.sync"):
+            tok_pf = None if tok_dev is None else np.asarray(tok_dev)
+            blk = (None if blk_dev is None
+                   else np.asarray(blk_dev).astype(np.int64))
+        t_done = time.monotonic_ns()
+        self._sync_ns = t_done - t_sync
+        self.stats["host_syncs"] += 1   # ONE fetch covers chunk AND block
+        if plan.chunks:
+            self.stats["prefills"] += 1
+            self.stats["prefill_dispatches"] += 1
+            self.stats["chunk_tokens"] += plan.prompt_tokens
+        # Sync "timeout": the device advanced but the host never
+        # harvested — a retry would re-decode past the recorded state,
+        # so this one is non-retryable: the watchdog fails the slots.
+        self._fault_raise("engine.sync", retryable=False)
+        with _span("serve.harvest"):
+            served = self._harvest_chunked(plan, tok_pf, blk, t_done * 1e-9)
+        return served, True
+
+    def _plan_chunked(self) -> Tuple[bool, Optional[_ChunkPlan]]:
+        """Plan the chunked tick's dispatch on the host: claim pages and
+        privatize shared ones for every row that will write, then choose
+        K.  Returns (anything moved, the plan or None when nothing is to
+        dispatch)."""
+        worked = False
         B, C = self.max_batch, self.chunk_tokens
         # 2) Assemble this dispatch's chunk rows.  The page reservation
         #    is extended to cover exactly the positions this chunk will
@@ -2243,6 +2335,7 @@ class ServeEngine:
         start_v = np.zeros((B,), np.int32)
         nval_v = np.zeros((B,), np.int32)
         chunks: List[Tuple[DecodeSlot, int, bool]] = []
+        prompt_tokens = 0
         for s in self.slots:
             if s.request is None or s.generated > 0:
                 continue
@@ -2287,6 +2380,10 @@ class ServeEngine:
             start_v[s.index] = s.prefill_pos
             nval_v[s.index] = v
             chunks.append((s, v, final))
+            # The bucket's left padding is dispatched but is no prompt.
+            pad = len(s.prompt) - len(req.prompt)
+            prompt_tokens += max(0, s.prefill_pos + v
+                                 - max(s.prefill_pos, pad))
         active = [s for s in self.slots
                   if s.request is not None and s.generated > 0]
         if self.prefix_cache is not None and active:
@@ -2309,8 +2406,7 @@ class ServeEngine:
                     worked = True
             active = still
         if not chunks and not active:
-            return served, worked
-        caches = self._take_caches()
+            return worked, None
         pos_v = self._pos.copy()
         for s, v, _ in chunks:
             # Streaming rows pass their POST-chunk extent: the decode
@@ -2346,50 +2442,40 @@ class ServeEngine:
             k = max(1, k)
         else:
             k = 0
-        # 3) ONE dispatch: chunk and K-step block fused when both exist.
-        # Dispatch probe sits here — after ALL host bookkeeping, before
-        # any device work — so a retried tick reassembles idempotently
-        # (extend claims 0 new pages, ensure_private finds nothing
-        # shared) and redispatches the identical work.
-        self._fault_raise("engine.dispatch")
-        t0 = time.monotonic()
-        tok_pf = blk = None
-        if chunks and k:
-            tok_dev, blk_dev, caches = self._chunked_fn(k)(
-                self.params, caches, jnp.asarray(chunk),
-                jnp.asarray(start_v), jnp.asarray(nval_v),
-                jnp.asarray(self._cur), jnp.asarray(pos_v),
-                jnp.asarray(rem_v), jnp.asarray(eos_v))
-            tok_pf = np.asarray(tok_dev)
-            blk = np.asarray(blk_dev).astype(np.int64)
-        elif chunks:
-            tok_dev, caches = self._chunked_fn(0)(
-                self.params, caches, jnp.asarray(chunk),
-                jnp.asarray(start_v), jnp.asarray(nval_v))
-            tok_pf = np.asarray(tok_dev)
-        else:
-            blk_dev, caches = self._loop_fn(k)(
-                self.params, caches, jnp.asarray(self._cur),
-                jnp.asarray(pos_v), jnp.asarray(rem_v),
-                jnp.asarray(eos_v))
-            blk = np.asarray(blk_dev).astype(np.int64)
-        self._give_caches(caches)
-        self.stats["host_syncs"] += 1   # ONE fetch covers chunk AND block
-        if chunks:
-            self.stats["prefills"] += 1
-            self.stats["prefill_dispatches"] += 1
-        t1 = time.monotonic()
-        # Sync "timeout": the device advanced but the host never
-        # harvested — a retry would re-decode past the recorded state,
-        # so this one is non-retryable: the watchdog fails the slots.
-        self._fault_raise("engine.sync", retryable=False)
+        return worked, _ChunkPlan(chunks, active, k,
+                                  (chunk, start_v, nval_v),
+                                  (self._cur, pos_v, rem_v, eos_v),
+                                  prompt_tokens)
+
+    def _dispatch_chunked(self, plan: _ChunkPlan, caches):
+        """Enqueue the plan's one program: chunk + K decode steps, chunk
+        only, or K decode steps.  Returns (prefill tokens or None, token
+        block or None, caches), all still on the device."""
+        args = [jnp.asarray(a) for a in plan.chunk_args] if plan.chunks else []
+        if plan.k:
+            args += [jnp.asarray(a) for a in plan.decode_args]
+        if plan.chunks and plan.k:
+            return self._chunked_fn(plan.k)(self.params, caches, *args)
+        if plan.chunks:
+            tok_dev, caches = self._chunked_fn(0)(self.params, caches, *args)
+            return tok_dev, None, caches
+        blk_dev, caches = self._loop_fn(plan.k)(self.params, caches, *args)
+        return None, blk_dev, caches
+
+    def _harvest_chunked(self, plan: _ChunkPlan, tok_pf: Optional[np.ndarray],
+                         blk: Optional[np.ndarray], now: float) -> int:
+        """Steps 4-5 of the chunked tick, on the host: the chunks' prefill
+        tokens, then the decode block; every token is stamped ``now``,
+        when the host received them.  Returns requests retired."""
+        served = 0
+        k = plan.k
         # 4) Harvest chunks.  A final chunk delivers the prefill's first
         #    token straight from the regular block fetch (exact TTFT, no
         #    dedicated host sync), flips the slot ALLOCATED, and — when
         #    the dispatch carried a decode block — the row's first K
         #    decode tokens are already in it (it joined on device).
         joined: List[DecodeSlot] = []
-        for s, v, final in chunks:
+        for s, v, final in plan.chunks:
             req = s.request
             s.prefill_pos += v
             self.stats["prefill_chunks"] += 1
@@ -2405,14 +2491,8 @@ class ServeEngine:
             self._cur[s.index] = tok
             s.outs[0] = tok
             s.generated = 1
-            # The first token came back with the block fetch: when the
-            # dispatch also decoded (k > 0) its timestamp is the first
-            # point of the dispatch's interpolation window, keeping
-            # token_ts monotone with the decode tokens that followed it
-            # on device; a chunk-only dispatch stamps real harvest time.
-            ts0 = (t0 + (t1 - t0) / (k + 1)) if k else time.monotonic()
-            req.first_token_t = ts0
-            req.token_ts.append(ts0)
+            req.first_token_t = now
+            req.token_ts.append(now)
             self.pool.note_tokens(req.req_id, s.pos)
             self._stream_tokens(req, 0, [tok])
             if self._finished(req, tok, s.generated, s.pos):
@@ -2424,9 +2504,8 @@ class ServeEngine:
                 joined.append(s)
         # 5) Harvest the decode block (continuing rows + joiners).
         if k:
-            served += self._harvest_block(active + joined, blk, k, t0, t1,
-                                          joined=tuple(joined))
-        return served, True
+            served += self._harvest_block(plan.active + joined, blk, k, now)
+        return served
 
     def _tick_scalar(self) -> Tuple[int, bool]:
         """The unfused baseline (scheduler="slot"): one decode step and
@@ -2837,7 +2916,9 @@ class ServeEngine:
                 self._reset_runtime()
                 self.pool.reset()
             raise SnapshotError(f"restore failed mid-rebuild: {exc!r}")
-        self.stats = dict(snap.stats)
+        # Over the defaults (all 0): a snapshot older than a counter
+        # restores with that counter at 0.
+        self.stats = {**dict.fromkeys(self.stats, 0), **snap.stats}
         self.stats["restores"] += 1
         self.stats["replayed_requests"] += replayed
         self.stats["restarts"] = self._restart_count
