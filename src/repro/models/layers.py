@@ -140,6 +140,24 @@ def is_paged(cache) -> bool:
     return isinstance(cache, dict) and "pages_k" in cache
 
 
+def _write_rows(pool: jax.Array, li, page: jax.Array, row: jax.Array,
+                new: jax.Array, src: jax.Array, ok: jax.Array) -> jax.Array:
+    """Write whole rows of ``pool`` [L, n_pages, rows, W] at layer ``li``.
+
+    ``page``/``row`` [B, R] address the rows; lane group ``u`` of row
+    ``r`` takes ``new[b, src[b, r, u]]`` ([B, T, F] tokens) where
+    ``ok[b, r, u]``, and keeps the pool's value elsewhere.  A page out of
+    range drops its row.  Whole rows, because the TPU scatters a window
+    that spans the minor axis natively, and one that starts inside it
+    as a loop of one update at a time."""
+    B, R, g = src.shape
+    old = pool[li, jnp.minimum(page, pool.shape[1] - 1), row]
+    upd = jnp.take_along_axis(new, src.reshape(B, R * g, 1), axis=1)
+    rows = jnp.where(ok[..., None], upd.reshape(B, R, g, -1),
+                     old.reshape(B, R, g, -1)).reshape(old.shape)
+    return pool.at[li, page, row].set(rows, mode="drop")
+
+
 def attention(p: Dict[str, Any], cfg: ModelConfig, x: jax.Array,
               positions: jax.Array,
               window: int = 0,
@@ -194,41 +212,52 @@ def attention(p: Dict[str, Any], cfg: ModelConfig, x: jax.Array,
         # layout — token sequences stay byte-identical to the dense
         # backend.  Pages are position-ordered, so a slot's kv position
         # IS its gather index: no ring arithmetic, no wrap epoch.
+        # The pool is [L, n_pages, rows, W] (``kv_cache.pool_shape``): a
+        # row packs g = W // (nkv*hd) tokens, token t of a page at row
+        # t // g, lanes (t % g) * nkv*hd onward.
         kp, vp = cache["pages_k"], cache["pages_v"]
         li, blockt = cache["layer"], cache["block"]
-        n_pages, ps = kp.shape[0], kp.shape[1]
+        n_pages, rows, width = kp.shape[1:]
+        g = width // (nkv * hd)
+        ps = rows * g
         cp = jnp.asarray(cache_pos)
         if cp.ndim == 0:
             raise ValueError("paged cache requires a [B] vector cache_pos "
                              "(per-row block tables)")
-        idx = cp[:, None] + jnp.arange(T)                   # [B, T] positions
-        page = jnp.take_along_axis(blockt, idx // ps, axis=1, mode="clip")
-        off = idx % ps
-        k_new = k.astype(kp.dtype)
-        v_new = v.astype(vp.dtype)
+        # The rows the T new tokens touch, in sequence order: row r holds
+        # positions r*g .. r*g+g-1; lane group u of it is chunk token t.
+        r = cp[:, None] // g + jnp.arange((T + 2 * g - 2) // g)   # [B, R]
+        page = jnp.take_along_axis(blockt, r // rows, axis=1, mode="clip")
+        t = r[:, :, None] * g + jnp.arange(g) - cp[:, None, None]
+        src = jnp.clip(t, 0, T - 1)                         # [B, R, g]
+        ok = (t >= 0) & (t < T)
         if write_mask is not None:
             # Rows not writing this dispatch (idle/dead decode rows, the
             # padded tail of a final chunk) must DROP their writes: in
             # the shared pool a masked row's junk write could land in a
             # page another sequence owns — unlike the dense cache, where
-            # each row's junk stays in its own private rows.  An
-            # out-of-range page index + scatter mode="drop" is the
-            # write-enable.
-            page = jnp.where(write_mask, page, n_pages)
+            # each row's junk stays in its own private rows.
+            ok &= jnp.take_along_axis(write_mask, src.reshape(B, -1),
+                                      axis=1).reshape(src.shape)
             total = cp[:, None] + jnp.sum(write_mask, axis=1,
                                           keepdims=True)   # [B, 1]
         else:
             total = cp[:, None] + T
-        kp = kp.at[page, off, li].set(k_new, mode="drop")
-        vp = vp.at[page, off, li].set(v_new, mode="drop")
+        # A row with nothing to write gets an out-of-range page: the
+        # scatter drops it (the write-enable).
+        page = jnp.where(ok.any(-1), page, n_pages)
+        k_new = k.astype(kp.dtype).reshape(B, T, nkv * hd)
+        v_new = v.astype(vp.dtype).reshape(B, T, nkv * hd)
+        kp = _write_rows(kp, li, page, r % rows, k_new, src, ok)
+        vp = _write_rows(vp, li, page, r % rows, v_new, src, ok)
         new_cache = {"pages_k": kp, "pages_v": vp, "block": blockt,
                      "layer": li}
         S = blockt.shape[1] * ps
-        # Gather ONLY this batch's pages at this layer ([B, P, ps, kv,
-        # hd] — O(B*max_len), not O(n_pages)): the jnp expression of the
+        # Gather ONLY this batch's pages at this layer ([B, P, rows, W]
+        # — O(B*max_len), not O(n_pages)): the jnp expression of the
         # Pallas kernel's per-page index_map DMA.
-        k = kp[blockt, :, li].reshape(B, S, nkv, hd)
-        v = vp[blockt, :, li].reshape(B, S, nkv, hd)
+        k = kp[li, blockt].reshape(B, S, nkv, hd)
+        v = vp[li, blockt].reshape(B, S, nkv, hd)
         kv_pos = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
         kv_valid = jnp.arange(S)[None, :] < total           # [B, S]
     elif cache is not None:

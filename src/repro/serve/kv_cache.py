@@ -1,8 +1,8 @@
 """Paged KV-cache pool with a lock-free refcounted page allocator.
 
 The serving engine's KV memory is a fixed pool of fixed-size pages (the
-vLLM idea, TPU-adapted: pages are [page_size, kv_heads, head_dim] tiles
-whose last two dims stay MXU/VREG aligned).  Page accounting uses the
+vLLM idea, TPU-adapted: each layer's share of a page is a few rows of
+128-lane multiples, see :func:`pool_shape`).  Page accounting uses the
 refcounted generalization of the paper's lock-free **bit set**
 (refactoring step 3): claim-from-zero is a single CAS on a
 :class:`RefCountArray` slot, share/release are wait-free fetch-add /
@@ -65,6 +65,69 @@ from repro.core.refcount import RefCountArray
 OK = 0
 POOL_FULL = 1
 
+LANES = 128                 # the TPU's vector width: a row of the pool
+
+
+def row_group(page_size: int, kv_heads: int, head_dim: int) -> int:
+    """Tokens per pool row: the least divisor ``g`` of ``page_size``
+    whose ``g * kv_heads * head_dim`` is a multiple of 128, else
+    ``page_size`` (one row per page)."""
+    width = kv_heads * head_dim
+    return next((g for g in range(1, page_size + 1)
+                 if page_size % g == 0 and g * width % LANES == 0),
+                page_size)
+
+
+def pool_shape(n_pages: int, page_size: int, n_layers: int, kv_heads: int,
+               head_dim: int) -> Tuple[int, int, int, int]:
+    """Shape of the pool's k (and v) array: ``[n_layers, n_pages, rows,
+    W]``, with ``g = row_group(...)``, ``rows = page_size // g`` and ``W =
+    g * kv_heads * head_dim``.  Token ``t`` of a page sits in row ``t //
+    g`` at lanes ``(t % g) * kv_heads * head_dim`` onward."""
+    g = row_group(page_size, kv_heads, head_dim)
+    return (n_layers, n_pages, page_size // g, g * kv_heads * head_dim)
+
+
+def _take_pages(a: jax.Array, idx: jax.Array) -> jax.Array:
+    """``a[:, idx]``, one page slice at a time: for a stablelm-3b pool
+    (rows of 2560) XLA lowers the plain gather on axis 1 through
+    temporaries the size of the whole pool."""
+    out = jnp.zeros(a.shape[:1] + idx.shape + a.shape[2:], a.dtype)
+    return jax.lax.fori_loop(
+        0, idx.shape[0],
+        lambda i, o: jax.lax.dynamic_update_slice_in_dim(
+            o, jax.lax.dynamic_slice_in_dim(a, idx[i], 1, axis=1), i,
+            axis=1), out)
+
+
+@jax.jit
+def pool_read_pages(k, v, idx):
+    """Pages ``idx`` of the pool arrays, ``[L, len(idx), rows, W]``."""
+    return _take_pages(k, idx), _take_pages(v, idx)
+
+
+def _copy_pages_program(n: int):
+    """The donated program copying ``n`` whole pages inside the pool
+    arrays (pages are axis 1)."""
+    def copy_pages(k, v, s, d):
+        return (k.at[:, d].set(_take_pages(k, s)),
+                v.at[:, d].set(_take_pages(v, s)))
+
+    # Named, so that a profiler trace shows which program it is.
+    copy_pages.__name__ = f"pool_copy_pages_n{n}"
+    return jax.jit(copy_pages, donate_argnums=(0, 1))
+
+
+def _write_pages_program(n: int):
+    """The donated program writing ``n`` host page images (``[n, L,
+    rows, W]``) into pages ``d`` of the pool arrays."""
+    def write_pages(k, v, d, kh, vh):
+        return (k.at[:, d].set(jnp.swapaxes(kh, 0, 1)),
+                v.at[:, d].set(jnp.swapaxes(vh, 0, 1)))
+
+    write_pages.__name__ = f"pool_write_pages_n{n}"
+    return jax.jit(write_pages, donate_argnums=(0, 1))
+
 
 @dataclasses.dataclass
 class PageTable:
@@ -86,9 +149,10 @@ class PageTable:
 class SwapImage:
     """Host-side parking record for one preempted sequence (DESIGN.md
     §12).  ``rows`` are the block-table rows whose private live pages
-    were gathered into ``k``/``v`` (numpy, one entry per row, in row
-    order); ``dead_rows`` were reserved-ahead (never-attended) pages
-    released without copying; ``shared_rows`` are refcount>1 prefix
+    were gathered into ``k``/``v`` (numpy page images from
+    :meth:`PagedKVPool.read_pages`, one entry per row, in row order);
+    ``dead_rows`` were reserved-ahead (never-attended) pages released
+    without copying; ``shared_rows`` are refcount>1 prefix
     pages that never moved — the sequence keeps its references and the
     block-table rows stay valid while parked."""
     seq_id: int
@@ -106,9 +170,18 @@ class SwapImage:
 class PagedKVPool:
     """One pool per (layer-stacked) KV tensor family.
 
-    k/v pools: [n_pages, page_size, n_layers, kv_heads, head_dim] — layer
-    innermost-batched so one page holds all layers for a token span and a
-    sequence needs ceil(len/page_size) pages total (not per layer).
+    k/v pools: ``pool_shape(...)`` = [n_layers, n_pages, rows, W] —
+    layer-major, and one page holds all layers for a span of
+    ``page_size`` tokens, so a sequence needs ceil(len/page_size) pages
+    total (not per layer).  A row packs ``g = row_group(...)`` tokens'
+    ``kv_heads * head_dim`` values into a width ``W`` that is a multiple
+    of 128 where a divisor of ``page_size`` allows it.  The layout is the
+    device's own: for a minor axis of ``head_dim`` under 128 the TPU
+    runtime keeps the page axis minor, so every step program began and
+    ended by relayouting the whole pool, and some did so per layer.
+    Pages are indexed on axis 1 here only (:meth:`read_pages`,
+    :meth:`write_pages`, the copy program); host images hold one entry
+    per page, in page order.
     """
 
     def __init__(self, n_pages: int, page_size: int, n_layers: int,
@@ -116,7 +189,8 @@ class PagedKVPool:
         self.n_pages, self.page_size = n_pages, page_size
         self.n_layers, self.kv_heads, self.head_dim = (n_layers, kv_heads,
                                                        head_dim)
-        shape = (n_pages, page_size, n_layers, kv_heads, head_dim)
+        shape = pool_shape(n_pages, page_size, n_layers, kv_heads, head_dim)
+        self.page_shape = (n_layers,) + shape[2:]   # one page's host image
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
         self._alloc = RefCountArray(n_pages)
@@ -192,7 +266,7 @@ class PagedKVPool:
     @property
     def page_nbytes(self) -> int:
         """Device bytes one page occupies across both pool arrays."""
-        return int(self.k[0].nbytes) + int(self.v[0].nbytes)
+        return 2 * math.prod(self.page_shape) * self.k.dtype.itemsize
 
     def reset_traffic(self) -> None:
         """Zero the copy/peak counters (benchmark pass boundaries)."""
@@ -287,13 +361,7 @@ class PagedKVPool:
         the pool arrays (donated, so XLA updates in place)."""
         fn = self._cow_fns.get(len(src))
         if fn is None:
-            def copy_pages(k, v, s, d):
-                return k.at[d].set(k[s]), v.at[d].set(v[s])
-
-            # Named, so that a profiler trace shows which program it is.
-            copy_pages.__name__ = f"pool_copy_pages_n{len(src)}"
-            fn = jax.jit(copy_pages, donate_argnums=(0, 1))
-            self._cow_fns[len(src)] = fn
+            fn = self._cow_fns[len(src)] = _copy_pages_program(len(src))
         s = jnp.asarray(src, jnp.int32)
         d = jnp.asarray(dst, jnp.int32)
         self.k, self.v = fn(self.k, self.v, s, d)
@@ -419,11 +487,8 @@ class PagedKVPool:
         """Gather a sequence's pages -> contiguous [max_len, L, kv, hd] k/v."""
         t = self._tables[seq_id]
         self.kv_copy_bytes += len(t.pages) * self.page_nbytes
-        idx = jnp.asarray(t.pages, jnp.int32)
-        k = self.k[idx].reshape(-1, self.n_layers, self.kv_heads,
-                                self.head_dim)
-        v = self.v[idx].reshape(-1, self.n_layers, self.kv_heads,
-                                self.head_dim)
+        k, v = map(self._token_major, pool_read_pages(
+            self.k, self.v, jnp.asarray(t.pages, jnp.int32)))
         pad = max_len - k.shape[0]
         if pad > 0:
             zk = jnp.zeros((pad,) + k.shape[1:], k.dtype)
@@ -446,13 +511,25 @@ class PagedKVPool:
             k_seq = jnp.concatenate([k_seq, zk])
             v_seq = jnp.concatenate([v_seq, zk])
         idx = jnp.asarray(t.pages[:n_pages], jnp.int32)
-        k_pages = k_seq[:n_pages * ps].reshape(n_pages, ps, self.n_layers,
-                                               self.kv_heads, self.head_dim)
-        v_pages = v_seq[:n_pages * ps].reshape(n_pages, ps, self.n_layers,
-                                               self.kv_heads, self.head_dim)
-        self.k = self.k.at[idx].set(k_pages)
-        self.v = self.v.at[idx].set(v_pages)
+        self.k = self.k.at[:, idx].set(self._page_major(k_seq[:n_pages * ps]))
+        self.v = self.v.at[:, idx].set(self._page_major(v_seq[:n_pages * ps]))
         return OK
+
+    def _token_major(self, x: jax.Array) -> jax.Array:
+        """Pool pages ``[L, n, rows, W]`` -> tokens ``[n*ps, L, kv, hd]``."""
+        L, n, rows, _ = x.shape
+        g = self.page_size // rows
+        x = x.reshape(L, n, rows, g, self.kv_heads, self.head_dim)
+        return x.transpose(1, 2, 3, 0, 4, 5).reshape(
+            n * self.page_size, L, self.kv_heads, self.head_dim)
+
+    def _page_major(self, x: jax.Array) -> jax.Array:
+        """Tokens ``[n*ps, L, kv, hd]`` -> pool pages ``[L, n, rows, W]``."""
+        L, rows, W = self.page_shape
+        n = x.shape[0] // self.page_size
+        x = x.reshape(n, rows, W // (self.kv_heads * self.head_dim), L,
+                      self.kv_heads, self.head_dim)
+        return x.transpose(3, 0, 1, 2, 4, 5).reshape(L, n, rows, W)
 
     # -- page-swap preemption (the overload tier, DESIGN.md §12) -------------
     def swap_out_preempt(self, seq_id: int, n_live_tokens: int) -> SwapImage:
@@ -498,15 +575,7 @@ class PagedKVPool:
                 rows.append(i)
             else:
                 dead_rows.append(i)
-        if rows:
-            idx = jnp.asarray([t.pages[i] for i in rows], jnp.int32)
-            k_host = np.asarray(self.k[idx])
-            v_host = np.asarray(self.v[idx])
-        else:
-            shape = (0, self.page_size, self.n_layers, self.kv_heads,
-                     self.head_dim)
-            k_host = np.zeros(shape, np.asarray(self.k[0:0]).dtype)
-            v_host = k_host
+        k_host, v_host = self.read_pages([t.pages[i] for i in rows])
         for i in rows + dead_rows:
             self._alloc.release(t.pages[i])
             t.pages[i] = -1
@@ -536,7 +605,7 @@ class PagedKVPool:
         for i, p in zip(image.rows + image.dead_rows, got):
             t.pages[i] = p
         if image.rows:
-            self._write_pages(got[:len(image.rows)], image.k, image.v)
+            self.write_pages(got[:len(image.rows)], image.k, image.v)
         nbytes = len(image.rows) * self.page_nbytes
         self.swap_in_bytes += nbytes
         self.kv_copy_bytes += nbytes
@@ -566,12 +635,7 @@ class PagedKVPool:
                 live = min(self.pages_needed(t.n_tokens), len(t.pages))
                 need.update(p for p in t.pages[:live] if p >= 0)
         idx = sorted(need)
-        if idx:
-            ii = jnp.asarray(idx, jnp.int32)
-            k_host = np.asarray(self.k[ii])
-            v_host = np.asarray(self.v[ii])
-        else:
-            k_host = v_host = np.zeros((0,), np.int8)
+        k_host, v_host = self.read_pages(idx)
         return {
             "n_pages": self.n_pages, "page_size": self.page_size,
             "refcounts": refcounts, "tables": tables,
@@ -593,8 +657,7 @@ class PagedKVPool:
         arrays (stale bytes from a previous incarnation must not leak
         into restored sequences), a fresh allocator, no tables, no
         quarantine, zero counters.  Compiled CoW/swap traces survive."""
-        shape = (self.n_pages, self.page_size, self.n_layers,
-                 self.kv_heads, self.head_dim)
+        shape = self.k.shape
         dtype = self.k.dtype
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
@@ -646,20 +709,27 @@ class PagedKVPool:
         self._shared_peak = c["shared_peak"]
         idx = state["data_pages"]
         if idx:
-            self._write_pages(idx, state["k"], state["v"])
+            self.write_pages(idx, state["k"], state["v"])
 
-    def _write_pages(self, dst: List[int], k_host, v_host) -> None:
+    # -- page accessors: the only code that knows the pages' axis -------------
+    def read_pages(self, pages: List[int]) -> Tuple[np.ndarray, np.ndarray]:
+        """Host images of whole pages, ``[len(pages), *page_shape]`` for
+        k and v, in the order given: one device fetch."""
+        if not len(pages):
+            empty = np.zeros((0,) + self.page_shape, self.k.dtype)
+            return empty, empty
+        k, v = pool_read_pages(self.k, self.v,
+                               jnp.asarray(pages, jnp.int32))
+        return (np.ascontiguousarray(np.asarray(k).swapaxes(0, 1)),
+                np.ascontiguousarray(np.asarray(v).swapaxes(0, 1)))
+
+    def write_pages(self, dst: List[int], k_host, v_host) -> None:
         """One fused device dispatch writing host images of whole pages
-        into pages ``dst`` of the pool arrays (donated, so XLA updates
-        in place)."""
+        (as :meth:`read_pages` returns them) into pages ``dst`` of the
+        pool arrays (donated, so XLA updates in place)."""
         fn = self._swap_fns.get(len(dst))
         if fn is None:
-            def write_pages(k, v, d, kh, vh):
-                return k.at[d].set(kh), v.at[d].set(vh)
-
-            write_pages.__name__ = f"pool_write_pages_n{len(dst)}"
-            fn = jax.jit(write_pages, donate_argnums=(0, 1))
-            self._swap_fns[len(dst)] = fn
+            fn = self._swap_fns[len(dst)] = _write_pages_program(len(dst))
         self.k, self.v = fn(self.k, self.v, jnp.asarray(dst, jnp.int32),
                             jnp.asarray(k_host), jnp.asarray(v_host))
 

@@ -111,11 +111,9 @@ def test_shed_status_is_falsy():
 # ---------------------------------------------------------------------------
 def _fill_pages(pool, pages, base):
     """Stamp identifiable values into whole pages of the pool arrays."""
-    idx = jnp.asarray(pages, jnp.int32)
-    shape = (len(pages),) + pool.k.shape[1:]
+    shape = (len(pages),) + pool.page_shape
     kv = base + jnp.arange(np.prod(shape), dtype=pool.k.dtype).reshape(shape)
-    pool.k = pool.k.at[idx].set(kv)
-    pool.v = pool.v.at[idx].set(kv + 0.5)
+    pool.write_pages(pages, kv, kv + 0.5)
     return np.asarray(kv), np.asarray(kv + 0.5)
 
 
@@ -136,10 +134,9 @@ def test_pool_preempt_roundtrip_byte_identical():
     assert pool.swap_in_preempt(1, img) == OK
     pages = pool.table(1).pages
     assert all(p >= 0 for p in pages)
-    np.testing.assert_array_equal(np.asarray(pool.k[jnp.asarray(pages)]),
-                                  want_k)
-    np.testing.assert_array_equal(np.asarray(pool.v[jnp.asarray(pages)]),
-                                  want_v)
+    got_k, got_v = pool.read_pages(pages)
+    np.testing.assert_array_equal(got_k, want_k)
+    np.testing.assert_array_equal(got_v, want_v)
     assert pool.swap_in_bytes == 3 * pool.page_nbytes
     assert pool.kv_copy_bytes == pool.swap_in_bytes + pool.swap_out_bytes
     pool.free(1)
@@ -170,7 +167,7 @@ def test_pool_preempt_never_moves_shared_pages():
     t = pool.table(1)
     shared = t.pages[0]
     pool.incref_pages([shared])                 # the cache's residency ref
-    want_k = np.asarray(pool.k[shared])
+    want_k = pool.read_pages([shared])[0]
     img = pool.swap_out_preempt(1, 12)
     assert img.shared_rows == [0] and img.rows == [1, 2]
     assert t.pages[0] == shared                 # row still valid, parked
@@ -179,7 +176,7 @@ def test_pool_preempt_never_moves_shared_pages():
     assert pool.cow_copy_bytes == 0
     assert pool.swap_in_preempt(1, img) == OK
     assert t.pages[0] == shared                 # never moved
-    np.testing.assert_array_equal(np.asarray(pool.k[shared]), want_k)
+    np.testing.assert_array_equal(pool.read_pages([shared])[0], want_k)
     assert pool.cow_copy_bytes == 0
     pool.free(1)                                # drops the seq's ref only
     assert pool.refcount(shared) == 1
@@ -240,7 +237,7 @@ if st is not None:
                     live[sid] = img.k.shape[0] * pool.page_size
                     pages = pool.table(sid).pages
                     np.testing.assert_array_equal(
-                        np.asarray(pool.k[jnp.asarray(pages)]), want[sid])
+                        pool.read_pages(pages)[0], want[sid])
             elif op == 3 and (live or parked):      # free newest
                 sid = max(list(live) + list(parked))
                 live.pop(sid, None)
@@ -249,7 +246,7 @@ if st is not None:
         for sid in live:                            # survivors unscathed
             pages = pool.table(sid).pages
             np.testing.assert_array_equal(
-                np.asarray(pool.k[jnp.asarray(pages)]), want[sid])
+                pool.read_pages(pages)[0], want[sid])
         for sid in list(live) + list(parked):
             pool.free(sid)
         assert pool.free_pages() == pool.n_pages    # nothing leaked

@@ -2,6 +2,8 @@
 pool as the device-resident KV store.  Token sequences must be
 byte-identical to the dense schedulers; residency must move zero KV
 bytes and scale with actual tokens, not max_len."""
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -10,6 +12,7 @@ from repro.configs import get_smoke_config
 from repro.core import states
 from repro.models.model import build_model
 from repro.serve.engine import ServeEngine
+from repro.serve.kv_cache import row_group
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -19,6 +22,30 @@ def engine_setup():
     cfg = get_smoke_config("smollm-135m")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
+    return cfg, model, params
+
+
+# The pool packs g tokens into a row of a multiple of 128 lanes
+# (``kv_cache.row_group``): g=1 where one token's kv_heads * head_dim
+# already fills whole rows, g=2 where two tokens share a row and odd
+# offsets land in its second half.
+ROW_GROUPS = {"g1": 64, "g2": 32}       # head_dim; the smoke has 2 kv heads
+
+
+@pytest.fixture(scope="module", params=sorted(ROW_GROUPS))
+def grouped_setup(request):
+    """A smoke model whose tokens depend on the attended KV: at init the
+    tied embedding outweighs attention and the model repeats its last
+    token whatever the cache holds, so attention's output is scaled up."""
+    cfg = dataclasses.replace(get_smoke_config("smollm-135m"),
+                              head_dim=ROW_GROUPS[request.param])
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    attn = params["layers"]["attn"]
+    params["layers"]["attn"] = {**attn, "wo": attn["wo"] * 8.0}
+    want = int(request.param[1:])
+    assert row_group(4, cfg.num_kv_heads, cfg.head_dim_) == want
+    assert row_group(16, cfg.num_kv_heads, cfg.head_dim_) == want
     return cfg, model, params
 
 
@@ -45,16 +72,17 @@ def _run_workload(model, params, scheduler, lengths, vocab, eos_id=-1,
     return eng, [got[r] for r in rids]
 
 
-def test_paged_equals_fused_across_chunk_sizes(engine_setup):
+def test_paged_equals_fused_across_chunk_sizes(grouped_setup):
     """The acceptance property: for chunk sizes 1, 4 and a whole
     bucketed prompt, slot_paged emits token sequences byte-identical to
     slot_fused — block-table indirection changes where KV lives, never
     the tokens — while performing ZERO KV copy traffic: no gather/
     scatter dispatch, no cache-copy dispatch, no dense batch cache."""
-    cfg, model, params = engine_setup
+    cfg, model, params = grouped_setup
     lengths = [12, 2, 7, 2, 1, 9, 24, 3]     # mixed, forces adaptive K
     e_fused, s_fused = _run_workload(model, params, "slot_fused", lengths,
                                      cfg.vocab_size)
+    assert any(len(set(s)) > 1 for s in s_fused)    # the KV steers tokens
     assert e_fused.pool.kv_copy_bytes > 0     # the copies paged deletes
     for chunk in (1, 4, 8):                   # prompts bucket to 8
         e_p, s_p = _run_workload(model, params, "slot_paged", lengths,
@@ -70,15 +98,16 @@ def test_paged_equals_fused_across_chunk_sizes(engine_setup):
         assert e_p.pool.free_pages() == e_p.pool.n_pages
 
 
-def test_paged_page_boundary_crossing_mid_block(engine_setup):
+def test_paged_page_boundary_crossing_mid_block(grouped_setup):
     """A fused K-step block whose decode positions cross page boundaries
     mid-block (page_size=4, K up to 8) scatters each token into the
     right (page, offset) — sequences stay identical to the scalar slot
     path and pages are accounted per boundary."""
-    cfg, model, params = engine_setup
+    cfg, model, params = grouped_setup
     lengths = [14, 3, 11]                     # crosses 3+ boundaries
     _, s_slot = _run_workload(model, params, "slot", lengths,
                               cfg.vocab_size, page_size=4)
+    assert any(len(set(s)) > 1 for s in s_slot)     # the KV steers tokens
     e_p, s_p = _run_workload(model, params, "slot_paged", lengths,
                              cfg.vocab_size, page_size=4, chunk_tokens=8,
                              k_max=8)
